@@ -30,6 +30,9 @@ def main() -> None:
     ap.add_argument("--only", nargs="*", help="run only these module labels")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         chaos_soak,
         compress_scaling,

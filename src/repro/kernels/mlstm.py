@@ -33,9 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -140,7 +137,7 @@ def mlstm_chunkwise_fwd(
             pltpu.VMEM((8, d), jnp.float32),
             pltpu.VMEM((8, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, gf, ff)
     return out.reshape(b, h, s, d)

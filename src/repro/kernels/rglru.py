@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _rglru_kernel(a_ref, x_ref, o_ref, h_scr, *, block_s: int):
     is_ = pl.program_id(2)
@@ -86,7 +83,7 @@ def rglru_scan_fwd(
         out_specs=pl.BlockSpec((1, block_s, block_w), lambda ib, iw, is_: (ib, is_, iw)),
         out_shape=jax.ShapeDtypeStruct((b, s2, w2), x.dtype),
         scratch_shapes=[pltpu.VMEM((8, block_w), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -47,9 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 LANES = 128
 SUBLANES = 8
@@ -188,7 +185,7 @@ def tiered_decode_attention_fwd(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, SUBLANES, d), q.dtype),
-        compiler_params=_compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens.astype(jnp.int32), qf,
       hot_k.reshape(b * kv, w_max, d), hot_v.reshape(b * kv, w_max, d),

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_reduced, make_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (
     make_prefill_step,
     make_serve_step,
@@ -147,6 +148,7 @@ def main() -> None:
     ap.add_argument("--host-id", type=int, default=1,
                     help="host id for --distributed (unique per process)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     dstore = None

@@ -8,7 +8,7 @@ failure the driver restores the last committed checkpoint AND the exact
 pipeline cursor, then continues — the recovery path is the paper's read
 mode (f): memory tier first, PFS fallback.
 
-CLI:  python -m repro.launch.train --arch starcoder2-3b --steps 20 --reduced
+CLI:  python -m repro.launch.train --arch starcoder2-3b --steps 20 --reduced --store <dir>
 """
 
 from __future__ import annotations
@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs import get_config, get_reduced, make_model
 from repro.core.store import TwoLevelStore
 from repro.data.pipeline import PipelineState, ShardedLoader, SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_state, make_train_step
 from repro.optim.adamw import AdamW, cosine_warmup
 from repro.runtime.checkpoint import CheckpointManager
@@ -42,6 +43,18 @@ class TrainResult:
     stalls: dict = dataclasses.field(default_factory=dict)
     #: accumulated two-level data-path stats across all loaders of the run
     loader_stats: dict = dataclasses.field(default_factory=dict)
+
+
+def jit_train_step(model, cfg, optimizer: AdamW, accum_steps: int = 1):
+    """``train_step(state, batch)`` compiled as :func:`run_training` runs it.
+
+    The state is donated, so the new state reuses the old one's buffers:
+    without that a step holds two copies of params and optimizer state,
+    which decides whether a real model's one-chip share fits the chip.
+    The caller must not read a state after passing it in.
+    """
+    return jax.jit(make_train_step(model, cfg, optimizer, accum_steps=accum_steps),
+                   donate_argnums=0)
 
 
 def run_training(
@@ -62,7 +75,7 @@ def run_training(
     """Train with checkpoint/restart through the two-level store."""
     model = make_model(cfg)
     optimizer = AdamW(learning_rate=cosine_warmup(peak_lr, 10, max(total_steps, 20)))
-    train_step = jax.jit(make_train_step(model, cfg, optimizer, accum_steps=accum_steps))
+    train_step = jit_train_step(model, cfg, optimizer, accum_steps)
 
     corpus = SyntheticCorpus(
         store, vocab_size=cfg.vocab, n_shards=8,
@@ -115,7 +128,13 @@ def run_training(
                         inputs, labels = next(loader)
                         t_data = time.perf_counter() - t0
                         batch = {"inputs": jnp.asarray(inputs), "labels": jnp.asarray(labels)}
-                        state, metrics = train_step(state, batch)
+                        # The step sees {params, opt, step} only: passing the
+                        # host-side cursor too, as the state carries it after
+                        # a restore or a save, gives the step a second
+                        # signature and so a second compile.
+                        state, metrics = train_step(
+                            {k: state[k] for k in ("params", "opt", "step")}, batch
+                        )
                         hb.beat()
                         loss = float(metrics["loss"])
                         losses.append(loss)
@@ -180,7 +199,8 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--store", default="/tmp/repro_store")
+    ap.add_argument("--store", required=True,
+                    help="store root; training resumes from any checkpoint found there")
     ap.add_argument("--ckpt-mode", default="async", choices=["sync", "async", "memory_only"])
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     ap.add_argument("--distributed", action="store_true",
@@ -195,6 +215,7 @@ def main() -> None:
                          "(see repro.runtime.failure.ChaosInjector)")
     ap.add_argument("--chaos-seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     chaos = None

@@ -1,11 +1,15 @@
-"""Distributed-runtime substrate: checkpointing, failure handling, stragglers."""
+"""Distributed-runtime substrate: checkpointing, failure handling, stragglers.
 
-from repro.runtime.checkpoint import CheckpointManager
+``CheckpointManager`` lives in :mod:`repro.runtime.checkpoint` and is not
+re-exported here: it needs JAX, and the store's host processes import
+:mod:`repro.runtime.failure` without ever touching a device (one process
+per chip).
+"""
+
 from repro.runtime.failure import FailureInjector, Heartbeat, SimulatedFailure
 from repro.runtime.straggler import StepTimeMonitor
 
 __all__ = [
-    "CheckpointManager",
     "FailureInjector",
     "Heartbeat",
     "SimulatedFailure",

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.runtime import CheckpointManager
+from repro.runtime.checkpoint import CheckpointManager
 
 
 def tree(seed=0):
@@ -192,7 +192,7 @@ import json, sys, tempfile
 import jax, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import TwoLevelStore
-from repro.runtime import CheckpointManager
+from repro.runtime.checkpoint import CheckpointManager
 
 rng = np.random.default_rng(0)
 state = {

@@ -139,3 +139,34 @@ class TestCompression:
             sparse, ef, _ = topk_compress_with_ef(small, ef, ratio=0.01)
             sent_total += np.asarray(sparse["w"])
         assert (sent_total[1:] > 0).any()  # small coords escaped via EF
+
+
+HOST_IMPORTS_SCRIPT = """
+import sys
+import benchmarks.chaos_soak, benchmarks.multihost_scaling
+from repro.core.dstore import DistributedStore
+from repro.core.resilience import CircuitOpen
+from repro.core.tiers import TierError
+from repro.data.pipeline import plan_shard_placement
+from repro.runtime.failure import ChaosInjector
+print("jax" in sys.modules)
+"""
+
+
+def test_store_host_processes_never_import_jax():
+    """The store's spawned host processes (multihost/chaos benchmarks, the
+    killed-owner test) import only these modules.  Without JAX in the
+    process they cannot initialise a backend, so they never contend for
+    the chip with the parent that holds it."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([os.path.join(root, "src"), root]))
+    proc = subprocess.run(
+        [sys.executable, "-c", HOST_IMPORTS_SCRIPT],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == "False"
