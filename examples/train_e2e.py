@@ -95,12 +95,12 @@ def main() -> None:
             print(f"checkpoint traffic to PFS tier: {st['pfs']['bytes_written']/2**20:.1f} MiB; "
                   f"async flushes: {st['store']['async_flushes']}")
 
-            s = res.stalls
+            s, n = res.stalls, max(res.steps_run, 1)
             print("\nstep stall breakdown (where the wall time went):")
             print(f"  data stall:  {s['data_stall_total_s']:7.2f}s total, "
-                  f"{s['data_stall_ewma_s']*1e3:7.2f}ms/step EWMA")
+                  f"{s['data_stall_total_s'] / n * 1e3:7.2f}ms/step mean")
             print(f"  ckpt stall:  {s['ckpt_stall_total_s']:7.2f}s total, "
-                  f"{s['ckpt_stall_ewma_s']*1e3:7.2f}ms/step EWMA "
+                  f"{s['ckpt_stall_total_s'] / n * 1e3:7.2f}ms/step mean "
                   f"(async save critical path {s['ckpt_save_critical_s']:.2f}s)")
 
             ls = res.loader_stats

@@ -98,6 +98,7 @@ from repro.core.tiers import (
     PFSTier,
     crc32_chunked,
 )
+from repro.core.trace import span
 
 
 class WriteMode(enum.Enum):
@@ -862,7 +863,12 @@ class TwoLevelStore:
                     self._dirty.add(bkey)
                     enqueue = True
             if enqueue:
-                self._flush_q.put(bkey)  # blocks when queue is full (bounded)
+                try:
+                    self._flush_q.put_nowait(bkey)
+                except queue.Full:
+                    # The write-back queue is bounded: wait for a flusher.
+                    with span("store.writeback_wait"):
+                        self._flush_q.put(bkey)
 
     # ----------------------------------------------------------------- codec
 

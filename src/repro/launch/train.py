@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 from typing import Callable
 
 import jax
@@ -24,13 +23,13 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced, make_model
 from repro.core.store import TwoLevelStore
+from repro.core.trace import span
 from repro.data.pipeline import PipelineState, ShardedLoader, SyntheticCorpus
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_state, make_train_step
 from repro.optim.adamw import AdamW, cosine_warmup
 from repro.runtime.checkpoint import CheckpointManager
 from repro.runtime.failure import FailureInjector, Heartbeat, SimulatedFailure
-from repro.runtime.straggler import StepTimeMonitor
 
 
 @dataclasses.dataclass
@@ -84,14 +83,11 @@ def run_training(
     corpus.generate()
     ckpt = CheckpointManager(store, tag=cfg.name, mode=ckpt_mode, keep_last=2)
     injector = injector or FailureInjector()
-    # One monitor per step phase: total step time, time stalled on the data
-    # plane (next(loader)), and time stalled on the checkpoint critical path
-    # (cursor sync + save).  In async mode the save stall is the device_get
-    # snapshot only — serialization and store puts run off the step path.
-    monitor = StepTimeMonitor(n_hosts=1)
-    data_monitor = StepTimeMonitor(n_hosts=1)
-    ckpt_monitor = StepTimeMonitor(n_hosts=1)
-    data_stall_s = ckpt_stall_s = 0.0
+    # Stall totals (seconds) from the loop's spans: time in next(loader),
+    # time on the checkpoint's critical path (cursor sync + save), and the
+    # part of it inside save() (the device->host snapshot in async mode:
+    # packing and store puts run off the step path).
+    data_stall_s = ckpt_stall_s = save_critical_s = 0.0
     agg_loader: dict[str, float] = {}
 
     def fold_loader_stats(loader: ShardedLoader) -> None:
@@ -124,38 +120,36 @@ def run_training(
                     while int(state["step"]) < total_steps:
                         step_no = int(state["step"])
                         injector.maybe_fail(step_no)
-                        t0 = time.perf_counter()
-                        inputs, labels = next(loader)
-                        t_data = time.perf_counter() - t0
+                        with span("train.data_wait", step_no) as waited:
+                            inputs, labels = next(loader)
+                        data_stall_s += waited.seconds
                         batch = {"inputs": jnp.asarray(inputs), "labels": jnp.asarray(labels)}
                         # The step sees {params, opt, step} only: passing the
                         # host-side cursor too, as the state carries it after
                         # a restore or a save, gives the step a second
                         # signature and so a second compile.
-                        state, metrics = train_step(
-                            {k: state[k] for k in ("params", "opt", "step")}, batch
-                        )
+                        with span("train.dispatch", step_no):
+                            state, metrics = train_step(
+                                {k: state[k] for k in ("params", "opt", "step")}, batch
+                            )
                         hb.beat()
-                        loss = float(metrics["loss"])
+                        with span("train.result_wait", step_no):
+                            loss = float(metrics["loss"])
                         losses.append(loss)
                         steps_run += 1
                         if on_step:
                             on_step(step_no, metrics)
-                        t_ckpt = 0.0
                         if int(state["step"]) % ckpt_every == 0:
-                            tc = time.perf_counter()
-                            cursor = loader.sync()
-                            state["pipeline"] = {
-                                "epoch": np.int64(cursor.epoch),
-                                "step": np.int64(cursor.step),
-                            }
-                            ckpt.save(int(state["step"]), state)
-                            t_ckpt = time.perf_counter() - tc
-                        monitor.record({0: time.perf_counter() - t0})
-                        data_monitor.record({0: t_data})
-                        ckpt_monitor.record({0: t_ckpt})
-                        data_stall_s += t_data
-                        ckpt_stall_s += t_ckpt
+                            saved = int(state["step"])
+                            with span("train.ckpt", saved) as stalled:
+                                cursor = loader.sync()
+                                state["pipeline"] = {
+                                    "epoch": np.int64(cursor.epoch),
+                                    "step": np.int64(cursor.step),
+                                }
+                                ckpt.save(saved, state)
+                            ckpt_stall_s += stalled.seconds
+                            save_critical_s += ckpt.last_save.seconds
                     break  # completed
                 except SimulatedFailure:
                     restarts += 1
@@ -175,12 +169,9 @@ def run_training(
     finally:
         ckpt.close()  # stop the background save lane (joins pending saves)
     stalls = {
-        "step_ewma_s": monitor.synchronous_step_time(),
-        "data_stall_ewma_s": data_monitor.synchronous_step_time(),
-        "ckpt_stall_ewma_s": ckpt_monitor.synchronous_step_time(),
         "data_stall_total_s": data_stall_s,
         "ckpt_stall_total_s": ckpt_stall_s,
-        "ckpt_save_critical_s": sum(ckpt.save_critical_s),
+        "ckpt_save_critical_s": save_critical_s,
     }
     return TrainResult(
         state=state,

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import json
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any
 
@@ -49,6 +48,7 @@ import numpy as np
 
 from repro.core.sched import StreamClass
 from repro.core.store import ReadMode, TwoLevelStore, WriteMode
+from repro.core.trace import span
 
 PyTree = Any
 
@@ -137,8 +137,9 @@ class CheckpointManager:
         self._bg = ThreadPoolExecutor(max_workers=1, thread_name_prefix="ckpt-save")
         self._pending: list[Future] = []
         self._pending_lock = threading.Lock()
-        #: wall seconds save() spent on the caller's critical path, per save
-        self.save_critical_s: list[float] = []
+        #: the newest save's ``ckpt.save`` span: ``.seconds`` is how long
+        #: save() held its caller
+        self.last_save: span | None = None
         # Elastic-arbiter staging ledger (DESIGN.md §13): host bytes of
         # async-save snapshots still queued/serializing on the lane.
         self._inflight_bytes = 0
@@ -166,34 +167,36 @@ class CheckpointManager:
         training state); chunk packing and store puts run on the
         background lane and ``save`` returns immediately.
         """
-        t0 = time.perf_counter()
-        named = [
-            (name, np.asarray(jax.device_get(leaf)))
-            for name, leaf in _flatten_with_names(state)
-        ]
-        if self.mode == "async":
-            # Surface failures of already-finished saves without blocking on
-            # the one still in flight — the critical path stays snapshot-only.
-            self._join_pending(wait=False)
-            nbytes = sum(a.nbytes for _, a in named)
-            if self._arb_pool is not None:
+        with span("ckpt.save", step) as held:
+            with span("ckpt.snapshot", step):
+                named = [
+                    (name, np.asarray(jax.device_get(leaf)))
+                    for name, leaf in _flatten_with_names(state)
+                ]
+            if self.mode == "async":
+                # Surface failures of already-finished saves without blocking
+                # on the one still in flight — the critical path stays
+                # snapshot-only.
+                self._join_pending(wait=False)
+                nbytes = sum(a.nbytes for _, a in named)
+                if self._arb_pool is not None:
+                    with self._pending_lock:
+                        over = self._inflight_bytes + nbytes > max(
+                            self._arb_pool.budget, nbytes
+                        )
+                    if over:
+                        # Staging budget exhausted: drain the lane before
+                        # snapshotting another copy — the arbiter throttles
+                        # async staging instead of letting it balloon.
+                        self._join_pending(wait=True)
                 with self._pending_lock:
-                    over = self._inflight_bytes + nbytes > max(
-                        self._arb_pool.budget, nbytes
-                    )
-                if over:
-                    # Staging budget exhausted: drain the lane before
-                    # snapshotting another copy — the arbiter throttles
-                    # async staging instead of letting it balloon.
-                    self._join_pending(wait=True)
-            with self._pending_lock:
-                self._inflight_bytes += nbytes
-            fut = self._bg.submit(self._bg_save, step, named, nbytes)
-            with self._pending_lock:
-                self._pending.append(fut)
-        else:
-            self._serialize_and_put(step, named)
-        self.save_critical_s.append(time.perf_counter() - t0)
+                    self._inflight_bytes += nbytes
+                fut = self._bg.submit(self._bg_save, step, named, nbytes)
+                with self._pending_lock:
+                    self._pending.append(fut)
+            else:
+                self._serialize_and_put(step, named)
+        self.last_save = held
 
     def _bg_save(self, step: int, named: list[tuple[str, np.ndarray]], nbytes: int) -> None:
         try:
@@ -231,7 +234,8 @@ class CheckpointManager:
         return pool
 
     def _serialize_and_put(self, step: int, named: list[tuple[str, np.ndarray]]) -> None:
-        leaves, chunks = _pack_chunks(named, self.chunk_bytes)
+        with span("ckpt.pack", step):
+            leaves, chunks = _pack_chunks(named, self.chunk_bytes)
         manifest = {"chunks": [len(c) for c in chunks], "leaves": leaves}
         mode = self._write_mode()
         prefix = self._prefix(step)

@@ -2,12 +2,14 @@
 checkpoint/restart, failure injection, and exact recovery."""
 
 import dataclasses
+import threading
 
 import numpy as np
 import pytest
 
 from repro.configs import get_reduced
-from repro.core import ReadMode, TwoLevelStore
+from repro.core import ReadMode, TwoLevelStore, WriteMode
+from repro.core import trace
 from repro.launch.train import run_training
 from repro.runtime.failure import FailureInjector
 
@@ -80,3 +82,72 @@ class TestEndToEnd:
         run_training(cfg, big_store, total_steps=5, ckpt_every=5, global_batch=8, ckpt_mode="sync")
         res = run_training(cfg, big_store, total_steps=8, ckpt_every=4, global_batch=4)
         assert int(res.state["step"]) == 8
+
+
+def _spans_after(fn):
+    """Run ``fn``; return its result and the spans recorded meanwhile."""
+    recs = trace.records()
+    start = recs[-1].seq if recs else -1
+    out = fn()
+    return out, [r for r in trace.records() if r.seq > start]
+
+
+def _inside(inner, outer) -> bool:
+    return inner.thread == outer.thread and outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+
+
+class TestSpans:
+    def test_each_step_and_save_records_its_spans(self, big_store):
+        res, recs = _spans_after(lambda: run_training(
+            small_cfg(), big_store, total_steps=4, ckpt_every=2, ckpt_mode="async"))
+        assert res.steps_run == 4
+        loop = {r.thread for r in recs if r.name == "train.dispatch"}
+        assert loop == {threading.current_thread().name}
+        for name in ("train.data_wait", "train.dispatch", "train.result_wait"):
+            assert [r.step for r in recs if r.name == name] == [0, 1, 2, 3], name
+        for saved in (2, 4):
+            (outer,) = [r for r in recs if r.name == "train.ckpt" and r.step == saved]
+            (save,) = [r for r in recs if r.name == "ckpt.save" and r.step == saved]
+            (snap,) = [r for r in recs if r.name == "ckpt.snapshot" and r.step == saved]
+            (pack,) = [r for r in recs if r.name == "ckpt.pack" and r.step == saved]
+            assert _inside(save, outer) and _inside(snap, save)
+            assert pack.thread.startswith("ckpt-save") and pack.t0 >= snap.t1
+        # the stall totals are the spans' sums
+        total = lambda name: sum(r.seconds for r in recs if r.name == name)
+        assert res.stalls == {
+            "data_stall_total_s": pytest.approx(total("train.data_wait"), abs=1e-12),
+            "ckpt_stall_total_s": pytest.approx(total("train.ckpt"), abs=1e-12),
+            "ckpt_save_critical_s": pytest.approx(total("ckpt.save"), abs=1e-12),
+        }
+
+    @pytest.mark.parametrize("depth,waits", [(1, True), (64, False)])
+    def test_a_full_write_back_queue_records_the_wait(self, tmp_path, depth, waits):
+        """One flusher held on its first block: with room for one queued
+        block the third put waits for the queue; with 64 none does."""
+        release = threading.Event()
+        with TwoLevelStore(str(tmp_path / "pfs"), mem_capacity_bytes=64 * 2**20,
+                           block_bytes=2**20, async_queue_depth=depth,
+                           flush_workers=1) as st:
+            flush = st._claim_and_flush
+
+            def held_flush(bkey):
+                assert release.wait(timeout=30)
+                flush(bkey)
+
+            st._claim_and_flush = held_flush
+            timer = threading.Timer(0.5, release.set)
+            timer.start()
+            try:
+                _, recs = _spans_after(lambda: st.put(
+                    "wb/file", bytes(8 * 2**20), mode=WriteMode.ASYNC_WRITEBACK))
+            finally:
+                release.set()
+                timer.cancel()
+            st.drain()
+            assert st.get("wb/file") == bytes(8 * 2**20)
+        waited = [r for r in recs if r.name == "store.writeback_wait"]
+        assert bool(waited) == waits
+        for r in waited:
+            assert r.thread == threading.current_thread().name and r.step is None
+        if waits:
+            assert sum(r.seconds for r in waited) >= 0.1
