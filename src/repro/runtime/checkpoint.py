@@ -22,6 +22,14 @@ Checkpoint layout inside the store (atomic-commit protocol, DESIGN.md §6)::
     ckpt/<tag>/step_00000042/COMMIT       written last; restore only sees
                                           committed steps
 
+A leaf that fills a chunk alone (every leaf of ``chunk_bytes`` or more)
+goes to the store as a flat byte view of its snapshot array, with no
+packed copy; only chunks shared by several small leaves are joined, and a
+leaf whose snapshot is not C-contiguous (one the device keeps in another
+layout, such as a column-major weight on a TPU) is copied into C order
+first.  The bytes stored are the same either way: the format does not
+depend on how a chunk was handed over.
+
 Chunks are written with one batched ``put_many`` (every block of every
 chunk in flight on the store's pool together) and restored with ranged
 reads: a leaf is fetched via ``get_range(chunk, offset, size)``, so a
@@ -67,44 +75,61 @@ def _flatten_with_names(tree: PyTree) -> list[tuple[str, Any]]:
     return [(_keystr(p), v) for p, v in leaves]
 
 
+def _byte_view(arr: np.ndarray) -> memoryview:
+    """The leaf's bytes in C order as a flat ``uint8`` view: no copy for a
+    C-contiguous leaf, one for any other.  Going through ``uint8`` lets
+    ``memoryview`` take dtypes the buffer protocol cannot name (bfloat16)."""
+    return memoryview(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
+
+
 def _pack_chunks(
     named: list[tuple[str, np.ndarray]], chunk_bytes: int
-) -> tuple[dict[str, dict], list[bytes]]:
+) -> tuple[dict[str, dict], list[bytes | memoryview], int]:
     """Greedy-pack leaf bytes into ~``chunk_bytes`` chunks, in leaf order.
 
     Every leaf lands whole inside exactly one chunk (an oversized leaf
     gets a chunk of its own), so restore can fetch it with a single
-    ranged read.  Returns (manifest leaves, chunk blobs).
+    ranged read.  A chunk holding one leaf is a view of that leaf's
+    array; only chunks shared by several leaves are joined into new
+    ``bytes``.  The views alias the arrays in ``named``, which must stay
+    alive and unchanged until the chunks are stored.  Returns (manifest
+    leaves, chunk buffers, bytes copied: joined, or made contiguous).
     """
     leaves: dict[str, dict] = {}
-    chunks: list[bytes] = []
-    parts: list[bytes] = []
+    chunks: list[bytes | memoryview] = []
+    parts: list[tuple[memoryview, bool]] = []  # (leaf bytes, already a copy)
     filled = 0
+    copied = 0
 
     def flush() -> None:
-        nonlocal parts, filled
-        if parts:
-            chunks.append(b"".join(parts))
-            parts = []
-            filled = 0
+        nonlocal parts, filled, copied
+        if len(parts) == 1:
+            raw, was_copied = parts[0]
+            chunks.append(raw)
+            copied += raw.nbytes if was_copied else 0
+        elif parts:
+            chunks.append(b"".join(raw for raw, _ in parts))
+            copied += filled
+        parts = []
+        filled = 0
 
     for name, arr in named:
-        raw = np.ascontiguousarray(arr).tobytes()
-        if filled and filled + len(raw) > chunk_bytes:
+        raw = _byte_view(arr)
+        if filled and filled + raw.nbytes > chunk_bytes:
             flush()
         leaves[name] = {
             "shape": list(arr.shape),
             "dtype": str(arr.dtype),
             "chunk": len(chunks),
             "offset": filled,
-            "size": len(raw),
+            "size": raw.nbytes,
         }
-        parts.append(raw)
-        filled += len(raw)
+        parts.append((raw, not arr.flags.c_contiguous))
+        filled += raw.nbytes
         if filled >= chunk_bytes:
             flush()
     flush()
-    return leaves, chunks
+    return leaves, chunks, copied
 
 
 class CheckpointManager:
@@ -140,6 +165,11 @@ class CheckpointManager:
         #: the newest save's ``ckpt.save`` span: ``.seconds`` is how long
         #: save() held its caller
         self.last_save: span | None = None
+        #: leaf bytes of all saves so far that reached the store as views of
+        #: the snapshot, and those packed by a copy (chunks shared by small
+        #: leaves joined, non-contiguous leaves made contiguous)
+        self.pack_view_bytes = 0
+        self.pack_copied_bytes = 0
         # Elastic-arbiter staging ledger (DESIGN.md §13): host bytes of
         # async-save snapshots still queued/serializing on the lane.
         self._inflight_bytes = 0
@@ -165,15 +195,24 @@ class CheckpointManager:
         Sync/memory_only: fully synchronous.  Async: the device→host leaf
         snapshot happens here (the only part that must see consistent
         training state); chunk packing and store puts run on the
-        background lane and ``save`` returns immediately.
+        background lane and ``save`` returns immediately.  The lane hands
+        the snapshot's own buffers to the store, so in async mode a leaf
+        already on the host is copied here: the caller may change it in
+        place as soon as ``save`` returns.
         """
+        lane = self.mode == "async"
         with span("ckpt.save", step) as held:
             with span("ckpt.snapshot", step):
                 named = [
-                    (name, np.asarray(jax.device_get(leaf)))
+                    (
+                        name,
+                        np.array(leaf)
+                        if lane and isinstance(leaf, np.ndarray)
+                        else np.asarray(jax.device_get(leaf)),
+                    )
                     for name, leaf in _flatten_with_names(state)
                 ]
-            if self.mode == "async":
+            if lane:
                 # Surface failures of already-finished saves without blocking
                 # on the one still in flight — the critical path stays
                 # snapshot-only.
@@ -235,7 +274,10 @@ class CheckpointManager:
 
     def _serialize_and_put(self, step: int, named: list[tuple[str, np.ndarray]]) -> None:
         with span("ckpt.pack", step):
-            leaves, chunks = _pack_chunks(named, self.chunk_bytes)
+            leaves, chunks, copied = _pack_chunks(named, self.chunk_bytes)
+        with self._pending_lock:
+            self.pack_copied_bytes += copied
+            self.pack_view_bytes += sum(m["size"] for m in leaves.values()) - copied
         manifest = {"chunks": [len(c) for c in chunks], "leaves": leaves}
         mode = self._write_mode()
         prefix = self._prefix(step)

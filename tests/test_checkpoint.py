@@ -185,6 +185,161 @@ class TestChunkedLayout:
         np.testing.assert_array_equal(got["params"]["w"], tree(3)["params"]["w"])
 
 
+
+# Chunk size of the zero-copy pack tests: leaves named ``big_*`` are larger
+# and get a chunk each (handed to the store as views); the ``small_*`` run
+# shares chunks (joined, a copy); ``big_transposed`` is non-contiguous, so
+# it is made contiguous (a copy) before it fills its chunk alone.
+PACK_CHUNK = 4096
+
+
+def mixed_state(seed=0):
+    rng = np.random.default_rng(seed)
+    return {
+        "big_f32": rng.normal(size=(32, 48)).astype(np.float32),
+        "big_bf16": jnp.asarray(rng.normal(size=(40, 64)), dtype=jnp.bfloat16),
+        "big_int32": rng.integers(-(2**31), 2**31 - 1, size=(1500,), dtype=np.int32),
+        "big_transposed": rng.normal(size=(24, 80)).astype(np.float32).T,
+        "small_a_scalar": np.float32(rng.normal()),
+        "small_b_bf16": rng.normal(size=(7, 5)).astype(jnp.bfloat16),
+        "small_c_int32": rng.integers(0, 100, size=(13,), dtype=np.int32),
+        "small_d_f32": rng.normal(size=(3, 9)).astype(np.float32),
+        "small_e_int64": np.int64(rng.integers(0, 2**40)),
+    }
+
+
+def reference_layout(state, chunk_bytes):
+    """The checkpoint a copying pack writes: each leaf's ``tobytes`` packed
+    greedily, in leaf order, into chunks of about ``chunk_bytes``."""
+    leaves, chunks, parts, filled = {}, [], [], 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        arr = np.asarray(leaf)
+        raw = np.ascontiguousarray(arr).tobytes()
+        if filled and filled + len(raw) > chunk_bytes:
+            chunks.append(b"".join(parts))
+            parts, filled = [], 0
+        leaves[jax.tree_util.keystr(path)] = {
+            "shape": list(arr.shape), "dtype": str(arr.dtype),
+            "chunk": len(chunks), "offset": filled, "size": len(raw),
+        }
+        parts.append(raw)
+        filled += len(raw)
+        if filled >= chunk_bytes:
+            chunks.append(b"".join(parts))
+            parts, filled = [], 0
+    if parts:
+        chunks.append(b"".join(parts))
+    return {"chunks": [len(c) for c in chunks], "leaves": leaves}, chunks
+
+
+def assert_bit_exact(got, want):
+    for (path, g), w in zip(
+        jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)
+    ):
+        g, w = np.asarray(g), np.asarray(w)
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), jax.tree_util.keystr(path)
+        assert g.tobytes() == w.tobytes(), jax.tree_util.keystr(path)
+
+
+def leaf_bytes(state, prefix):
+    return sum(np.asarray(v).nbytes for k, v in state.items() if k.startswith(prefix))
+
+
+class TestZeroCopyPack:
+    def test_reference_layout_gives_big_leaves_their_own_chunks(self):
+        man, _ = reference_layout(mixed_state(), PACK_CHUNK)
+        by_chunk = {}
+        for name, meta in man["leaves"].items():
+            by_chunk.setdefault(meta["chunk"], []).append(name)
+        alone = {names[0] for names in by_chunk.values() if len(names) == 1}
+        assert alone == {f"['{k}']" for k in mixed_state() if k.startswith("big_")}
+
+    @pytest.mark.parametrize("mode", ["sync", "async", "memory_only"])
+    def test_store_holds_the_copying_pack_bytes(self, store, mode):
+        import json
+
+        cm = CheckpointManager(store, tag="z", mode=mode, chunk_bytes=PACK_CHUNK)
+        state = mixed_state()
+        cm.save(5, state)
+        cm.wait_until_durable()
+        want_manifest, want_chunks = reference_layout(state, PACK_CHUNK)
+        prefix = "ckpt/z/step_00000005"
+        assert json.loads(store.get(f"{prefix}/manifest").decode()) == want_manifest
+        names = sorted(n for n in store.list_files() if n.startswith(f"{prefix}/chunk_"))
+        assert names == [f"{prefix}/chunk_{i:04d}" for i in range(len(want_chunks))]
+        for name, want in zip(names, want_chunks):
+            assert store.get(name) == want, name
+        assert store.get(f"{prefix}/COMMIT") == str(len(want_chunks)).encode()
+
+    @pytest.mark.parametrize("mode", ["sync", "async", "memory_only"])
+    def test_restore_is_bit_exact(self, store, mode):
+        cm = CheckpointManager(store, tag="z", mode=mode, chunk_bytes=PACK_CHUNK)
+        state = mixed_state(1)
+        cm.save(2, state)
+        step, got = cm.restore(state)
+        assert step == 2
+        assert_bit_exact(got, state)
+
+    @pytest.mark.parametrize("mode", ["sync", "async", "memory_only"])
+    def test_only_small_and_noncontiguous_leaves_are_copied(self, store, mode):
+        cm = CheckpointManager(store, tag="z", mode=mode, chunk_bytes=PACK_CHUNK)
+        state = mixed_state(2)
+        copied = leaf_bytes(state, "small_") + state["big_transposed"].nbytes
+        viewed = leaf_bytes(state, "big_") - state["big_transposed"].nbytes
+        cm.save(1, state)
+        cm.wait_until_durable()
+        assert (cm.pack_copied_bytes, cm.pack_view_bytes) == (copied, viewed)
+        cm.save(2, state)  # the counters are cumulative
+        cm.wait_until_durable()
+        assert (cm.pack_copied_bytes, cm.pack_view_bytes) == (2 * copied, 2 * viewed)
+
+    def test_checkpoint_from_the_copying_pack_restores(self, store):
+        """A checkpoint written by the copying pack (same format) restores."""
+        import json
+
+        state = mixed_state(3)
+        manifest, chunks = reference_layout(state, PACK_CHUNK)
+        prefix = "ckpt/z/step_00000011"
+        batch = {f"{prefix}/chunk_{i:04d}": c for i, c in enumerate(chunks)}
+        batch[f"{prefix}/manifest"] = json.dumps(manifest).encode()
+        store.put_many(batch)
+        store.put(f"{prefix}/COMMIT", str(len(chunks)).encode())
+        step, got = CheckpointManager(store, tag="z", chunk_bytes=PACK_CHUNK).restore(state)
+        assert step == 11
+        assert_bit_exact(got, state)
+
+    def test_async_save_owns_its_snapshot(self, store):
+        """The lane reads the snapshot after save() returns: a donating step
+        on the device leaves and in-place writes to the caller's host leaves
+        made meanwhile must not reach the checkpoint."""
+        import threading
+
+        rng = np.random.default_rng(4)
+        params = {
+            "w": jnp.asarray(rng.normal(size=(64, 32)), dtype=jnp.float32),
+            "e": jnp.asarray(rng.normal(size=(48, 64)), dtype=jnp.bfloat16),
+        }
+        host = {
+            "cursor": np.arange(3, dtype=np.int64),
+            "table": rng.normal(size=(40, 40)).astype(np.float32),
+        }
+        saved = jax.tree_util.tree_map(np.array, {"params": params, "host": host})
+        step_fn = jax.jit(
+            lambda p: jax.tree_util.tree_map(lambda x: x * 3 + 1, p), donate_argnums=0
+        )
+        cm = CheckpointManager(store, tag="z", mode="async", chunk_bytes=PACK_CHUNK)
+        gate = threading.Event()
+        cm._bg.submit(gate.wait)  # hold the lane until the writes below are done
+        cm.save(1, {"params": params, "host": host})
+        params = jax.block_until_ready(step_fn(params))
+        host["cursor"] += 100
+        host["table"][:] = -1.0
+        gate.set()
+        cm.wait_until_durable()
+        _, got = cm.restore(saved)
+        assert_bit_exact(got, saved)
+        assert not np.array_equal(np.asarray(params["w"]), saved["params"]["w"])
+
 ELASTIC_SUBPROCESS_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
