@@ -46,6 +46,23 @@ def load_json(path: Path) -> Any:
         raise BenchError(f"missing file {path}") from None
 
 
+#: a configuration file's own blocks; every other top-level key of a file
+#: with no ``hf`` block is a key of the published config, under its
+#: published name (as a catalog model's file holds them)
+CONFIG_BLOCKS = ("name", "source", "deployment", "program_arch", "published", "reduced", "assumed",
+                 "architecture", "precision", "program", "init", "train", "store", "limits")
+
+
+def load_config(path: Path) -> dict:
+    """A configuration file, with its published keys as ``hf``: the file's
+    ``hf`` block or, where it has none, its top-level keys but its own
+    blocks."""
+    conf = load_json(path)
+    if "hf" not in conf:
+        conf["hf"] = {k: v for k, v in conf.items() if k not in CONFIG_BLOCKS}
+    return conf
+
+
 def load_module(path: Path):
     """Import a file of the benchmark by path (names may hold dots)."""
     if not path.is_file():
@@ -86,7 +103,7 @@ def load_cell(name: str, spec: dict | None = None, bench_dir: Path = BENCH) -> C
     configs = {c["name"]: c for c in spec["configs"]}
     if w["config"] not in configs:
         raise BenchError(f"workload {name!r} names unknown config {w['config']!r}")
-    config = load_json(bench_dir.parent / configs[w["config"]]["file"])
+    config = load_config(bench_dir.parent / configs[w["config"]]["file"])
     traffic = load_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name)]
     e2e_names = {m["name"] for m in e2e}

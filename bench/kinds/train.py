@@ -242,12 +242,17 @@ def reference_readings(conf: dict, model, cfg, seed: int, batches: list[dict],
     zeros = jax.jit(lambda t: jax.tree_util.tree_map(jnp.zeros_like, t))
     m, v = zeros(params), zeros(params)
 
-    def row_grad(acc, p, x, y, n):
-        loss, g = jax.value_and_grad(ref.lm_loss)(p, x[None], y[None], conf_s, quant)
+    def row_grad(acc, p, x, y, stats, n):
+        loss, g = jax.value_and_grad(ref.lm_loss)(p, x[None], y[None], conf_s, quant, *stats)
         return jax.tree_util.tree_map(lambda a, b: a + b / n, acc, g), loss / n
 
     conf_s = ref._Frozen(conf)
-    row_grad = jax.jit(row_grad, static_argnums=(4,), donate_argnums=(0,))
+    row_grad = jax.jit(row_grad, static_argnums=(5,), donate_argnums=(0,))
+    # A loss term over the whole batch (a load-balance term's expert shares)
+    # takes the family's ``batch_stats``, the mean of its rows' readings.
+    row_stats = getattr(ref, "batch_stats", None)
+    if row_stats is not None:
+        row_stats = jax.jit(row_stats, static_argnums=(2, 3))
     norms = _jit_norms()
 
     def step_fn(p, g, m_, v_, count):
@@ -261,10 +266,13 @@ def reference_readings(conf: dict, model, cfg, seed: int, batches: list[dict],
         xs, ys = np.asarray(b["inputs"]), np.asarray(b["labels"])
         if rows is not None:
             xs, ys = xs[rows], ys[rows]
+        stats = ()
+        if row_stats is not None:
+            stats = (sum(row_stats(params, jnp.asarray(x)[None], conf_s, quant) for x in xs) / len(xs),)
         g = zeros(params)
         loss = 0.0
         for r in range(xs.shape[0]):
-            g, lr_ = row_grad(g, params, jnp.asarray(xs[r]), jnp.asarray(ys[r]), xs.shape[0])
+            g, lr_ = row_grad(g, params, jnp.asarray(xs[r]), jnp.asarray(ys[r]), stats, xs.shape[0])
             loss += float(lr_)
         params, m, v, gn = step_fn(params, g, m, v, count)
         losses.append(loss)
@@ -448,7 +456,7 @@ def drive(ctx):
     counters = {
         "train.tokens": steps * batch * seq,
         "train.steps": steps,
-        "train.flops_per_token": flops.train_flops_per_token(conf["hf"], seq),
+        "train.flops_per_token": flops.train_flops_per_token(conf["hf"], seq, conf.get("published")),
         "pfs.bytes_written": pfs1["bytes_written"] - pfs0["bytes_written"],
         "pfs.write_busy_s": busy(pfs1) - busy(pfs0),
     }

@@ -13,6 +13,7 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
+DATA = Path(__file__).resolve().parent / "data"
 for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
@@ -44,6 +45,14 @@ def tiny_cell(name: str) -> harness.Cell:
     if tr["saves_in_window"]:
         tr["save_s"] = 0.0
     conf["limits"]["train"] = dict(TINY_TRAIN_LIMITS)
+    return cell
+
+
+def family_cell(config_file: str, name: str = "train.ckpt") -> harness.Cell:
+    """The cell ``name`` at toy sizes with its configuration replaced by a
+    tiny one kept under ``data/``, which holds its own store and limits."""
+    cell = tiny_cell(name)
+    cell.config = harness.load_config(DATA / config_file)
     return cell
 
 
