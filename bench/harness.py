@@ -7,7 +7,9 @@ It builds the system under test, warms every shape its traffic uses, calls
 ``ctx.close_window()`` when it ends, and then decides ``correct`` against its
 plain reference.  A per-layer reader (``bench/metrics/<metric>.py``) exposes
 ``read(rec) -> float | None`` over the :class:`RunRecord` of a traced run;
-``None`` means it found nothing to read, and the metric is left out.
+``None`` means it found nothing to read, and the metric is left out.  A
+reader of a kernel's device time takes ``rec.trace.scope_seconds(name)``, the
+self seconds of the ops the program traced under ``jax.named_scope(name)``.
 """
 
 from __future__ import annotations
@@ -366,6 +368,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             out["device"]["busy_s"] = summary.busy_s
             out["device"]["window_s"] = summary.window_s
             out["breakdown"] = summary.breakdown()
+            notes.append(f"device op seconds with an op_name (named scopes can count them): "
+                         f"{summary.scope_coverage * 100:.3f}%")
     out["checks"] = {c.name: {"value": c.value, "limit": c.limit} for c in res.checks}
     out["_notes"] = notes
     return out

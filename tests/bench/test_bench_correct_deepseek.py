@@ -32,8 +32,8 @@ def test_a_broken_expert_layer_is_not_correct(tmp_path, monkeypatch, fault):
     import repro.nn.layers as L
 
     moe_apply = L.moe_apply
-    monkeypatch.setattr(L, "moe_apply", lambda p, x, cfg: moe_apply(
-        p, x, dataclasses.replace(cfg, moe=fault(cfg.moe))))
+    monkeypatch.setattr(L, "moe_apply", lambda p, x, cfg, *a, **kw: moe_apply(
+        p, x, dataclasses.replace(cfg, moe=fault(cfg.moe)), *a, **kw))
     res = bt.run_tiny(bt.family_cell(CONFIG), tmp_path)
     assert not res["correct"]
     failed = {k for k, c in res["checks"].items() if c["value"] > c["limit"]}

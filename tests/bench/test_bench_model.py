@@ -87,12 +87,33 @@ def test_a_catalog_file_holds_its_published_keys_at_the_top_level():
     assert harness.load_config(QWEN)["hf"]["hidden_size"] == 4096  # an ``hf`` block stays as it is
 
 
-def test_the_moonlight_share_needs_the_programs_expert_share():
+def _stand_in(cls, drop=(), **types):
+    """``cls`` as a new frozen dataclass without the fields ``drop`` and
+    with the annotations ``types`` given."""
+    return dataclasses.make_dataclass(cls.__name__, [
+        (f.name, types.get(f.name, f.type),
+         dataclasses.field(default=f.default, default_factory=f.default_factory))
+        for f in dataclasses.fields(cls) if f.name not in drop], frozen=True)
+
+
+@pytest.fixture
+def program_without_shares(monkeypatch):
+    """The program's MoE and MLA configs without the fields a chip's expert
+    share, the routed scaling and a null query rank need, whether or not the
+    program has them, so that the refusals are tested as such."""
+    from repro.configs import base, get_config
+
+    get_config("deepseek_v3_671b")  # imported before the patch, so it keeps the program's classes
+    monkeypatch.setattr(base, "MoEConfig", _stand_in(base.MoEConfig, ("experts_held", "routed_scale")))
+    monkeypatch.setattr(base, "MLAConfig", _stand_in(base.MLAConfig, q_lora_rank="int"))
+
+
+def test_the_moonlight_share_needs_the_programs_expert_share(program_without_shares):
     with pytest.raises(harness.BenchError, match="MoEConfig.experts_held"):
         bm.arch_config(harness.load_config(MOONLIGHT))
 
 
-def test_a_null_query_rank_needs_a_direct_query_projection():
+def test_a_null_query_rank_needs_a_direct_query_projection(program_without_shares):
     conf = harness.load_config(MOONLIGHT)
     conf["hf"].update(n_routed_experts=64, routed_scaling_factor=1.0)
     with pytest.raises(harness.BenchError, match="q_lora_rank null"):
@@ -102,22 +123,38 @@ def test_a_null_query_rank_needs_a_direct_query_projection():
 @pytest.fixture
 def program_with_shares(monkeypatch):
     """The program's MoE and MLA configs with the fields a chip's expert
-    share, the routed scaling and a null query rank need."""
+    share, the routed scaling and a null query rank need: the program's own
+    where it has them."""
     from repro.configs import base, get_config
 
     get_config("deepseek_v3_671b")  # imported before the patch, so it keeps the program's classes
+    if not {"experts_held", "routed_scale"} <= {f.name for f in dataclasses.fields(base.MoEConfig)}:
 
-    @dataclasses.dataclass(frozen=True)
-    class MoE(base.MoEConfig):
-        experts_held: int = 0
-        routed_scale: float = 1.0
+        @dataclasses.dataclass(frozen=True)
+        class MoE(base.MoEConfig):
+            experts_held: int = 0
+            routed_scale: float = 1.0
 
-    @dataclasses.dataclass(frozen=True)
-    class MLA(base.MLAConfig):
-        q_lora_rank: int | None = 1536
+        monkeypatch.setattr(base, "MoEConfig", MoE)
+    q_rank = next(f for f in dataclasses.fields(base.MLAConfig) if f.name == "q_lora_rank")
+    if "None" not in str(q_rank.type):
 
-    monkeypatch.setattr(base, "MoEConfig", MoE)
-    monkeypatch.setattr(base, "MLAConfig", MLA)
+        @dataclasses.dataclass(frozen=True)
+        class MLA(base.MLAConfig):
+            q_lora_rank: int | None = 1536
+
+        monkeypatch.setattr(base, "MLAConfig", MLA)
+
+
+#: the MoEConfig and MLAConfig fields a configuration file maps (its published
+#: keys and ``program.moe``'s ``capacity_factor``); the program's may hold more
+MOE_MAPPED = ("n_experts", "top_k", "expert_ff", "n_shared", "capacity_factor", "router_type",
+              "normalize_gates", "first_k_dense")
+MLA_MAPPED = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+
+
+def _fields_of(obj, names) -> dict:
+    return {n: getattr(obj, n) for n in names}
 
 
 def test_the_moonlight_share_maps_every_published_key(program_with_shares):
@@ -125,9 +162,9 @@ def test_the_moonlight_share_maps_every_published_key(program_with_shares):
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab) == (6, 2048, 16, 11264, 20480)
     assert (cfg.attn_type, cfg.mtp, cfg.tie_embeddings, cfg.rope_theta, cfg.norm_eps) == (
         "mla", False, False, 50000, 1e-05)
-    assert dataclasses.asdict(cfg.mla) == {"q_lora_rank": None, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
-                                           "qk_rope_head_dim": 64, "v_head_dim": 128}
-    assert dataclasses.asdict(cfg.moe) == {
+    assert _fields_of(cfg.mla, MLA_MAPPED) == {"q_lora_rank": None, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+                                               "qk_rope_head_dim": 64, "v_head_dim": 128}
+    assert _fields_of(cfg.moe, MOE_MAPPED + ("experts_held", "routed_scale")) == {
         "n_experts": 64, "experts_held": 8, "top_k": 6, "expert_ff": 1408, "n_shared": 2,
         "capacity_factor": 10.7, "router_type": "sigmoid", "normalize_gates": True, "first_k_dense": 1,
         "routed_scale": 2.446}
@@ -138,7 +175,8 @@ def test_the_tiny_family_file_maps_onto_the_programs_reduced_shape():
 
     cfg = bm.arch_config(harness.load_config(TINY_DS))
     red = get_reduced("deepseek_v3_671b")
-    assert (cfg.mla, cfg.moe) == (red.mla, red.moe)
+    assert _fields_of(cfg.mla, MLA_MAPPED) == _fields_of(red.mla, MLA_MAPPED)
+    assert _fields_of(cfg.moe, MOE_MAPPED) == _fields_of(red.moe, MOE_MAPPED)
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab) == (
         red.n_layers, red.d_model, red.n_heads, red.d_ff, red.vocab)
     assert cfg.attn_type == "mla" and not cfg.mtp
