@@ -71,7 +71,13 @@ def run_training(
     on_step: Callable[[int, dict], None] | None = None,
     accum_steps: int = 1,
 ) -> TrainResult:
-    """Train with checkpoint/restart through the two-level store."""
+    """Train with checkpoint/restart through the two-level store.
+
+    The host's turn before a dispatch overlaps the step ahead of it on the
+    device: the step number is counted on the host, not read back, and
+    step n+1's batch is taken from the loader and uploaded before step n's
+    loss is read.
+    """
     model = make_model(cfg)
     optimizer = AdamW(learning_rate=cosine_warmup(peak_lr, 10, max(total_steps, 20)))
     train_step = jit_train_step(model, cfg, optimizer, accum_steps)
@@ -107,6 +113,13 @@ def run_training(
     restarts = 0
     steps_run = 0
 
+    def fetch(loader: ShardedLoader, step_no: int) -> dict:
+        nonlocal data_stall_s
+        with span("train.data_wait", step_no) as waited:
+            inputs, labels = next(loader)
+        data_stall_s += waited.seconds
+        return {"inputs": jnp.asarray(inputs), "labels": jnp.asarray(labels)}
+
     try:
         with Heartbeat(timeout_s=heartbeat_timeout) as hb:
             while True:
@@ -116,14 +129,15 @@ def run_training(
                 loader = ShardedLoader(
                     corpus, global_batch, seq_len, prefetch_depth=2, state=pstate
                 )
+                # Read from the device once per fresh or restored state:
+                # reading it each step would wait for the step just dispatched.
+                step_no = int(state["step"])
+                batch = None  # step_no's batch, when taken while the step before ran
                 try:
-                    while int(state["step"]) < total_steps:
-                        step_no = int(state["step"])
+                    while step_no < total_steps:
                         injector.maybe_fail(step_no)
-                        with span("train.data_wait", step_no) as waited:
-                            inputs, labels = next(loader)
-                        data_stall_s += waited.seconds
-                        batch = {"inputs": jnp.asarray(inputs), "labels": jnp.asarray(labels)}
+                        if batch is None:
+                            batch = fetch(loader, step_no)
                         # The step sees {params, opt, step} only: passing the
                         # host-side cursor too, as the state carries it after
                         # a restore or a save, gives the step a second
@@ -133,21 +147,30 @@ def run_training(
                                 {k: state[k] for k in ("params", "opt", "step")}, batch
                             )
                         hb.beat()
+                        next_no = step_no + 1
+                        saving = next_no % ckpt_every == 0
+                        # Take the next batch while this step runs; after a
+                        # save step only once the save is done, so that the
+                        # saved loader cursor stands at it.
+                        batch = None
+                        if next_no < total_steps and not saving:
+                            batch = fetch(loader, next_no)
                         with span("train.result_wait", step_no):
                             loss = float(metrics["loss"])
                         losses.append(loss)
                         steps_run += 1
                         if on_step:
                             on_step(step_no, metrics)
-                        if int(state["step"]) % ckpt_every == 0:
-                            saved = int(state["step"])
-                            with span("train.ckpt", saved) as stalled:
+                        step_no = next_no
+                        if saving:
+                            assert int(state["step"]) == next_no, (int(state["step"]), next_no)
+                            with span("train.ckpt", next_no) as stalled:
                                 cursor = loader.sync()
                                 state["pipeline"] = {
                                     "epoch": np.int64(cursor.epoch),
                                     "step": np.int64(cursor.step),
                                 }
-                                ckpt.save(saved, state)
+                                ckpt.save(next_no, state)
                             ckpt_stall_s += stalled.seconds
                             save_critical_s += ckpt.last_save.seconds
                     break  # completed

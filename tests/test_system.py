@@ -3,6 +3,7 @@ checkpoint/restart, failure injection, and exact recovery."""
 
 import dataclasses
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -151,3 +152,65 @@ class TestSpans:
             assert r.thread == threading.current_thread().name and r.step is None
         if waits:
             assert sum(r.seconds for r in waited) >= 0.1
+
+
+def _hooked_run(store, **kw):
+    """``run_training`` with an ``on_step`` that records each call's step,
+    loss and time; returns the result, the calls and the loop's spans."""
+    calls = []
+    hook = lambda s, m: calls.append((s, float(m["loss"]), time.perf_counter()))
+    res, recs = _spans_after(lambda: run_training(small_cfg(), store, on_step=hook, **kw))
+    by = lambda name: {r.step: r for r in recs if r.name == name}
+    return res, calls, by
+
+
+class TestOverlappedHostTurn:
+    """The next step's batch is taken and uploaded while the step before it
+    runs; each step's loss is read before the next dispatch, and the batch
+    after a save step is taken once the save is done."""
+
+    def test_the_next_batch_is_taken_before_the_result_is_read(self, big_store):
+        res, _, by = _hooked_run(big_store, total_steps=5, ckpt_every=100)
+        data, dispatch, result = by("train.data_wait"), by("train.dispatch"), by("train.result_wait")
+        assert res.steps_run == 5 and sorted(result) == sorted(data) == [0, 1, 2, 3, 4]
+        for n in range(4):
+            assert dispatch[n].t1 <= data[n + 1].t0 and data[n + 1].t1 <= result[n].t0, n
+            assert result[n].t1 <= dispatch[n + 1].t0, n
+
+    def test_a_save_step_is_finished_before_its_save(self, big_store):
+        res, calls, by = _hooked_run(big_store, total_steps=6, ckpt_every=3,
+                                     ckpt_mode="async")
+        data, dispatch, result, ckpt = (by(n) for n in (
+            "train.data_wait", "train.dispatch", "train.result_wait", "train.ckpt"))
+        hooked = {s: t for s, _, t in calls}
+        assert sorted(ckpt) == [3, 6]
+        # step 2 is saved as 3: its result and hook come first; step 3's
+        # batch is taken, and step 3 dispatched, once the save is done
+        assert result[2].t1 <= hooked[2] <= ckpt[3].t0
+        assert ckpt[3].t1 <= data[3].t0 and data[3].t1 <= dispatch[3].t0
+        # the overlap resumes after the save: step 4's batch before step 3's result
+        assert data[4].t1 <= result[3].t0
+        # the last step is finished, its hook called, before the run ends
+        assert result[5].t1 <= hooked[5] <= ckpt[6].t0
+        assert res.steps_run == 6 and int(res.state["step"]) == 6
+
+    def test_each_step_is_handed_to_the_hook_once_in_order(self, big_store):
+        res, calls, _ = _hooked_run(big_store, total_steps=7, ckpt_every=3)
+        assert [s for s, _, _ in calls] == list(range(7))
+        assert [loss for _, loss, _ in calls] == res.losses
+        assert res.steps_run == len(res.losses) == 7
+
+    def test_a_failure_replays_from_the_saved_cursor(self, tmp_path):
+        """Failing before step 7's dispatch, with its batch already taken:
+        the run restores step 5 and the loader's cursor there, and replays
+        5 and 6 with the first run's losses, bit for bit."""
+        kw = dict(total_steps=10, ckpt_every=5, ckpt_mode="sync")
+        with TwoLevelStore(str(tmp_path / "a"), mem_capacity_bytes=64 * 2**20) as st:
+            clean, clean_calls, _ = _hooked_run(st, **kw)
+        with TwoLevelStore(str(tmp_path / "b"), mem_capacity_bytes=64 * 2**20) as st:
+            failed, calls, _ = _hooked_run(st, injector=FailureInjector([7]), **kw)
+        assert failed.restarts == 1 and failed.steps_run == 12
+        assert [s for s, _, _ in calls] == [0, 1, 2, 3, 4, 5, 6, 5, 6, 7, 8, 9]
+        assert [loss for _, loss, _ in calls] == failed.losses
+        assert failed.losses == clean.losses[:7] + clean.losses[5:]
+        assert int(failed.state["step"]) == 10
