@@ -37,8 +37,7 @@ their re-blocking buffers) with the rest headroom for the emit batch —
 which is double-counted while live (concat + sorted copies) — so
 tracked engine buffers stay ≤ 2× budget at full occupancy.  The engine tracks every
 buffer it allocates in a ledger — ``ShuffleStats.peak_buffer_bytes`` is
-the acceptance-gate quantity (``benchmarks/terasort_scaling.py`` gates
-it ≤ 2× budget).
+the bound's witness (``tests/test_shuffle.py`` holds it ≤ 2× budget).
 
 The engine is workload-agnostic: records are fixed-size byte rows whose
 leading ``key_bytes`` fold into a uint64 sort key.  TeraSort is the

@@ -15,9 +15,9 @@ the out-of-core shuffle engine (``apps/shuffle.py``):
   materializing a partition.
 
 Phase wall-times + spill/merge stats + store tier stats are returned so
-the fig7 / terasort_scaling benchmarks can compare HDFS-style
-(memory-only), OrangeFS-style (PFS bypass) and two-level (tiered)
-storage on real moved bytes.
+a caller (``examples/terasort.py``) can compare HDFS-style (memory-only),
+OrangeFS-style (PFS bypass) and two-level (tiered) storage on real moved
+bytes.
 """
 
 from __future__ import annotations

@@ -53,7 +53,7 @@ class RecurrentConfig:
 @dataclasses.dataclass(frozen=True)
 class EncDecConfig:
     """Encoder-decoder (whisper) extras; the conv/audio frontend is a stub —
-    ``input_specs`` provides precomputed frame embeddings."""
+    the batch's ``frames`` carry precomputed frame embeddings."""
 
     n_encoder_layers: int = 32
     n_frames: int = 1500  # 30 s of audio after the conv frontend
@@ -63,7 +63,7 @@ class EncDecConfig:
 @dataclasses.dataclass(frozen=True)
 class VLMConfig:
     """Vision-language (InternVL) extras; the ViT frontend is a stub —
-    ``input_specs`` provides precomputed patch embeddings."""
+    the batch's ``patches`` carry precomputed patch embeddings."""
 
     n_patches: int = 256
     patch_dim: int = 1024  # InternViT-300M output width

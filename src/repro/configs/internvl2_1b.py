@@ -2,8 +2,8 @@
 [arXiv:2404.16821; hf].
 
 Assignment row: 24L d_model=896 14H (GQA kv=2) d_ff=4864 vocab=151655.
-The ViT frontend is a STUB — ``input_specs`` supplies precomputed patch
-embeddings (B, 256, 1024), projected and prepended to the token stream.
+The ViT frontend is a STUB — the batch's ``patches`` carry precomputed
+patch embeddings (B, 256, 1024), projected and prepended to the token stream.
 Qwen2 details: attention q/k/v biases, RMSNorm, SwiGLU, tied embeddings.
 14 heads do NOT divide the 16-way model axis: the shard-if-divisible rule
 replicates the head axis and shards d_ff (4864 = 16 x 304) instead.

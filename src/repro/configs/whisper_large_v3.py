@@ -3,7 +3,7 @@ unverified].
 
 Assignment row: 32L d_model=1280 20H (kv=20 -> MHA) d_ff=5120 vocab=51866.
 32 encoder + 32 decoder layers (the published model); the conv/mel
-frontend is a STUB — ``input_specs`` supplies precomputed frame
+frontend is a STUB — the batch's ``frames`` carry precomputed frame
 embeddings (B, 1500, 1280).  LayerNorm, plain-GELU MLP, biases, learned
 decoder positions, no RoPE.  max_seq_len sized for the decode_32k cell
 (the published 448-token decoder context is a fine-tuning choice, not an

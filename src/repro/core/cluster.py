@@ -173,12 +173,6 @@ def tpu_v5e_pod(n_hosts: int = 64, n_storage: int = 16) -> ClusterSpec:
     )
 
 
-# TPU v5e single-chip roofline constants (used by benchmarks/roofline.py).
-TPU_V5E_PEAK_BF16_FLOPS = 197e12  # FLOP/s per chip
-TPU_V5E_HBM_BW = 819e9  # bytes/s per chip
-TPU_V5E_ICI_BW = 50e9  # bytes/s per link
-
-
 def human_mbps(x: float) -> str:
     if x >= 1000.0:
         return f"{x/1000.0:.2f} GB/s"

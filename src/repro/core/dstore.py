@@ -1488,8 +1488,8 @@ class DistributedStore:
         with self._stats_lock:
             if self._repair_events:
                 # Repair events ride the gossip board (DESIGN.md §15): peers
-                # see which keys were healed where, and the benchmarks can
-                # assert cluster-wide repair visibility without new RPCs.
+                # see which keys were healed where, and a test can assert
+                # cluster-wide repair visibility without new RPCs.
                 payload["repairs"] = list(self._repair_events)
         self.gossip.publish(payload)
         if ctrl is not None:

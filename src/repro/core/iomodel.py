@@ -197,10 +197,7 @@ def f_for_read_mbps(nu: float, q: float, target: float) -> float:
 # a cold read moves ``1/ratio`` physical bytes over the PFS link and then
 # pays a decode pass — two serialized stages, so the effective cold rate
 # is their harmonic composition.  Substituted into Eq. 7, the same blend
-# shape holds with q replaced by q_eff; solving that blend back through
-# ``f_for_read_mbps`` with the *raw* q gives an "effective f" — the
-# residency an uncompressed store would need to match — which is how a
-# ratio-r codec buys model-visible capacity without new hardware.
+# shape holds with q replaced by q_eff.
 # ---------------------------------------------------------------------------
 
 
@@ -218,24 +215,6 @@ def effective_cold_read_mbps(q: float, ratio: float, decode_mbps: float | None =
     if decode_mbps is None or decode_mbps <= 0:
         return link if ratio != 1.0 else q
     return 1.0 / (1.0 / link + 1.0 / decode_mbps)
-
-
-def effective_read_mbps(
-    nu: float, q: float, f: float, ratio: float = 1.0, decode_mbps: float | None = None
-) -> float:
-    """Eq. 7 with the cold leg running at the compression-adjusted rate."""
-    q_eff = effective_cold_read_mbps(q, ratio, decode_mbps)
-    return blend_read_mbps(nu, q_eff, f)
-
-
-def effective_f(
-    nu: float, q: float, f: float, ratio: float = 1.0, decode_mbps: float | None = None
-) -> float:
-    """The in-memory fraction an *uncompressed* store would need to match
-    a compressed store running at physical residency ``f`` — compression's
-    capacity gain expressed in the paper's own variable."""
-    rate = effective_read_mbps(nu, q, f, ratio, decode_mbps)
-    return f_for_read_mbps(nu, q, min(rate, nu))
 
 
 def compression_wins(q: float, ratio: float, decode_mbps: float | None = None) -> bool:

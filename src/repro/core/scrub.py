@@ -8,8 +8,7 @@ repair queue fed by the read path's degraded-read hook, so a key that
 just failed over gets healed ahead of the next full pass.
 
 Two pacing mechanisms keep foreground p99 bounded while the scrubber
-runs — the acceptance gate in ``benchmarks/repair_scaling.py`` measures
-exactly this:
+runs:
 
 * **Lane gate** — at most one object is scrubbed at a time, through the
   controller's ``scrub_gate`` (an :class:`~repro.core.sched.AdaptiveGate`,

@@ -196,7 +196,7 @@ def reduce_scaling(spec: ClusterSpec, data_nodes: list[int], cfg: TeraSortConfig
 
 
 def mountain_summary(surface: dict[tuple[float, float], float]) -> dict[str, float]:
-    """Headline features of the mountain for tests/benchmarks."""
+    """Headline features of the mountain: its ridge and its worst point."""
     ridge_hi = max(v for (d, s), v in surface.items() if s == 0.0)
     ridge_lo = min(v for (d, s), v in surface.items() if s == 0.0)
     worst = min(surface.values())

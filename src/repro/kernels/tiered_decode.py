@@ -29,9 +29,7 @@ The kernel is **ring-aware** and **length-dynamic**:
 
 The kernel merges both tiers with one online softmax.  The effective
 read time follows the paper's harmonic model with
-``f = hot_len / (hot_len + cold_len)`` and rates (VMEM bw, HBM bw) — the
-benchmark in ``benchmarks/fig5_crossover.py`` reuses Eq. 7 with TPU
-constants for exactly this kernel.
+``f = hot_len / (hot_len + cold_len)`` and rates (VMEM bw, HBM bw).
 
 Layout: q (B, H, 1, D) — a decode step; cold (B, KV, C, D) HBM-streamed
 paged capacity buffer; hot (B, KV, W, D) VMEM-pinned ring.  Key order is

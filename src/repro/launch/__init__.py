@@ -1,1 +1,1 @@
-"""Launchers: mesh construction, dry-run, training and serving drivers."""
+"""Launchers: mesh construction, training and serving drivers."""

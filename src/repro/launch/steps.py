@@ -1,10 +1,9 @@
-"""Step builders: loss, train_step, prefill/serve steps, input specs,
-and sharding resolution for states/batches/caches.
+"""Step builders: loss, train_step, prefill/serve steps, and sharding
+resolution for states and batches.
 
 Everything here is mesh-agnostic until ``*_shardings`` binds a Mesh via
 the shard-if-divisible rules (``repro.nn.module``) — this is what lets a
-single code path lower on 1 CPU device, a 256-chip pod, or the 512-chip
-dual-pod mesh.
+single code path lower on 1 CPU device or a multi-chip mesh.
 """
 
 from __future__ import annotations
@@ -16,8 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ArchConfig, ShapeCell
-from repro.launch.mesh import dp_axis_names, dp_size
+from repro.configs.base import ArchConfig
+from repro.launch.mesh import dp_axis_names
 from repro.nn.module import logical_to_pspec
 from repro.optim.adamw import AdamW, apply_updates
 
@@ -280,78 +279,6 @@ def tiered_cache_stats(caches: dict) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Input specs (ShapeDtypeStruct stand-ins; no allocation)
-# ---------------------------------------------------------------------------
-
-
-def _sds(shape, dtype) -> jax.ShapeDtypeStruct:
-    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
-
-
-def batch_specs(cfg: ArchConfig, cell: ShapeCell) -> dict:
-    """Train/prefill batch ShapeDtypeStructs for one shape cell."""
-    b, s = cell.global_batch, cell.seq_len
-    if cfg.encdec is not None:
-        spec = {
-            "frames": _sds((b, cfg.encdec.n_frames, cfg.encdec.frame_dim), cfg.dtype),
-            "inputs": _sds((b, s), jnp.int32),
-        }
-    elif cfg.vlm is not None:
-        text = s - cfg.vlm.n_patches
-        spec = {
-            "inputs": _sds((b, text), jnp.int32),
-            "patches": _sds((b, cfg.vlm.n_patches, cfg.vlm.patch_dim), cfg.dtype),
-        }
-    else:
-        spec = {"inputs": _sds((b, s), jnp.int32)}
-    if cell.kind == "train":
-        label_s = spec["inputs"].shape[1]
-        spec["labels"] = _sds((b, label_s), jnp.int32)
-    return spec
-
-
-def cache_specs(model, cfg: ArchConfig, cell: ShapeCell) -> PyTree:
-    """Abstract KV-cache/recurrent-state tree for a decode/prefill cell."""
-    b = cell.global_batch
-    max_seq = cell.seq_len
-    if cfg.vlm is not None:
-        max_seq = max_seq  # patches included in cell seq_len budget
-    dtype = jnp.dtype(cfg.dtype)
-
-    def build():
-        return model.init_caches(b, max_seq, dtype)
-
-    caches = jax.eval_shape(build)
-    if cfg.encdec is not None:
-        # decode-time cross KV comes from prefill; build its abstract shape
-        hd = cfg.resolved_head_dim
-        cross = {
-            "k": _sds((cfg.n_layers, b, cfg.encdec.n_frames, cfg.n_kv_heads, hd), dtype),
-            "v": _sds((cfg.n_layers, b, cfg.encdec.n_frames, cfg.n_kv_heads, hd), dtype),
-        }
-        caches = {"self": caches["self"], "cross": cross}
-    return caches
-
-
-def token_specs(cfg: ArchConfig, cell: ShapeCell) -> jax.ShapeDtypeStruct:
-    return _sds((cell.global_batch, 1), jnp.int32)
-
-
-def input_specs(model, cfg: ArchConfig, cell: ShapeCell) -> dict:
-    """All abstract inputs for the cell's step function (the dry-run entry).
-
-    train  -> {"batch": ...}
-    prefill-> {"batch": ..., "caches": ...}
-    decode -> {"token": ..., "caches": ...}
-    """
-    if cell.kind == "train":
-        return {"batch": batch_specs(cfg, cell)}
-    if cell.kind == "prefill":
-        return {"batch": batch_specs(cfg, cell), "caches": cache_specs(model, cfg, cell)}
-    return {"token": token_specs(cfg, cell), "caches": cache_specs(model, cfg, cell)}
-
-
-# ---------------------------------------------------------------------------
 # Sharding resolution
 # ---------------------------------------------------------------------------
 
@@ -390,47 +317,6 @@ def batch_shardings(batch_shapes: PyTree, mesh: Mesh) -> PyTree:
         return NamedSharding(mesh, P(lead, *([None] * (len(leaf.shape) - 1))))
 
     return jax.tree_util.tree_map(one, batch_shapes)
-
-
-def cache_shardings(cache_shapes: PyTree, cfg: ArchConfig, mesh: Mesh, seq_shard: bool = False) -> PyTree:
-    """Cache sharding: batch over DP, head-like dims over 'model' when
-    divisible. ``seq_shard=True`` shards the cache sequence dim over
-    'model' instead (long-context lever for kv=1 archs; §Perf)."""
-    dp = dp_axis_names(mesh)
-
-    def one(path, leaf):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        shape = leaf.shape
-        rank = len(shape)
-        # Stacked layer dim(s) first? detect: caches under "periods"/"self"
-        # have a leading layers dim added by vmap/scan stacking.
-        stacked = any(
-            getattr(p, "key", None) in ("periods", "self", "cross") for p in path
-        )
-        spec: list = [None] * rank
-        if name == "index":
-            return NamedSharding(mesh, P(*([None] * rank)))
-        off = 1 if stacked else 0
-        bdim = off  # batch position
-        if rank > bdim:
-            spec[bdim] = _shard_if(shape[bdim], dp, mesh)
-        if name in ("k", "v"):
-            # (layers?, B, S, KV, hd)
-            if seq_shard and rank >= bdim + 2:
-                spec[bdim + 1] = _shard_if(shape[bdim + 1], ("model",), mesh)
-            elif rank >= bdim + 3:
-                spec[bdim + 2] = _shard_if(shape[bdim + 2], ("model",), mesh)
-        elif name in ("c_kv", "k_pe"):
-            if seq_shard and rank >= bdim + 2:
-                spec[bdim + 1] = _shard_if(shape[bdim + 1], ("model",), mesh)
-        elif name in ("h", "conv"):  # rglru states: (..., W) width last
-            spec[rank - 1] = _shard_if(shape[rank - 1], ("model",), mesh)
-        elif name in ("C", "n"):  # mlstm: (..., H, dh[, dh])
-            if rank >= bdim + 2:
-                spec[bdim + 1] = _shard_if(shape[bdim + 1], ("model",), mesh)
-        return NamedSharding(mesh, P(*spec))
-
-    return jax.tree_util.tree_map_with_path(one, cache_shapes)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -1,7 +1,7 @@
 """Encoder-decoder transformer (whisper-large-v3 backbone).
 
-The audio conv frontend is a STUB per the assignment: ``input_specs``
-provides precomputed frame embeddings (B, n_frames, d_model) — the
+The audio conv frontend is a STUB per the assignment: the batch's
+``frames`` carry precomputed frame embeddings (B, n_frames, d_model) — the
 mel-spectrogram conv stack's output.  Faithful whisper details kept:
 LayerNorm, GELU MLP, biases, learned decoder positions, sinusoidal
 encoder positions, MHA (n_kv == n_heads), tied decoder embedding/head,

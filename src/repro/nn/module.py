@@ -9,7 +9,7 @@ Logical axes resolve to mesh ``PartitionSpec``s through a rules table
 (``DEFAULT_RULES``) with a **shard-if-divisible** guard: a dim whose size
 does not divide its mesh axis is replicated instead (required for e.g.
 InternVL's 14 heads on a 16-way model axis).  This guarantee is what makes
-every (arch × mesh) combination lower and compile in the dry-run.
+every (arch × mesh) combination lower and compile.
 
 ``init_with_axes(fn, key, ...)`` runs an init function under
 ``jax.eval_shape`` when abstract=True, so 671 B-parameter models cost no
@@ -137,7 +137,7 @@ def init_with_axes(
     """Run ``init_fn`` under a fresh Scope; return (params, axes) trees.
 
     ``abstract=True`` runs under ``jax.eval_shape`` — no memory is
-    allocated; params come back as ShapeDtypeStructs (dry-run path).
+    allocated; params come back as ShapeDtypeStructs (compile-only path).
     """
     axes_box: dict = {}
 
@@ -160,7 +160,7 @@ def stacked_init(scope: Scope, name: str, n: int, init_fn: Callable[[Scope], Non
 
     The substrate for scan-over-layers: the stacked params feed
     ``jax.lax.scan``, keeping HLO size and compile time O(1) in depth
-    (61-layer dry-runs would be intractable unrolled).  Axes gain a
+    (61-layer compiles would be intractable unrolled).  Axes gain a
     leading "layers" logical axis (never sharded by DEFAULT_RULES).
     """
     keys = jax.random.split(scope.next_key(), n)

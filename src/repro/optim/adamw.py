@@ -2,7 +2,7 @@
 
 Self-contained (no optax): state is a plain pytree so the two-level
 checkpoint manager serializes it unchanged, and ``init`` is traceable so
-abstract (dry-run) state costs no memory.
+abstract (compile-only) state costs no memory.
 """
 
 from __future__ import annotations

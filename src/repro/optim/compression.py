@@ -9,8 +9,7 @@ Integration point: on a real multi-host mesh this wraps the per-bucket
 transform itself is jit-compatible; correctness (EF accumulation ->
 unbiased long-run updates) is property-tested in
 ``tests/test_compression.py``, and the collective-byte saving is entered
-as a modeled term in DESIGN.md §13 alongside the block-codec accounting
-(measured counterparts live in ``benchmarks/README.md``).
+as a modeled term in DESIGN.md §13 alongside the block-codec accounting.
 """
 
 from __future__ import annotations

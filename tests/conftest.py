@@ -1,9 +1,9 @@
 import os
 
-# Tests must see the real single CPU device — never the 512 dry-run
-# placeholders (the dry-run sets XLA_FLAGS in its own process only).
+# Tests must see the real single CPU device — never forced host-platform
+# placeholders (a four-device rehearsal sets XLA_FLAGS in its own process).
 assert "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""), (
-    "run pytest without the dry-run XLA_FLAGS"
+    "run pytest without --xla_force_host_platform_device_count in XLA_FLAGS"
 )
 
 import pytest

@@ -1,27 +1,34 @@
 """Resilient data plane under injected faults: stale-socket recovery,
 owner-death forwarded puts, takeover races, torn writes, circuit-breaker
-degradation, background reclamation, heartbeat pause, lease corruption."""
+degradation, background reclamation, heartbeat pause, lease corruption,
+and a zero-data-loss soak of real host processes under a mixed fault
+schedule ending in a hard host kill."""
 
 from __future__ import annotations
 
+import multiprocessing as mp
 import os
 import threading
 import time
+import traceback
+import zlib
 
+import numpy as np
 import pytest
 
 from repro.core.dstore import DistributedStore, LeaseLost, NotOwner
-from repro.core.resilience import CircuitBreaker
+from repro.core.resilience import CircuitBreaker, CircuitOpen
 from repro.core.store import ReadMode, WriteMode
-from repro.core.tiers import IntegrityError
+from repro.core.tiers import IntegrityError, TierError
 from repro.runtime.failure import ChaosInjector, SimulatedFailure
 
 MB = 2**20
 TTL = 1.0
 
-# Fault-injection soaks wait out real lease TTLs and retry backoffs; CI
-# runs `-m slow` in its own step with a wider per-test timeout.
-pytestmark = pytest.mark.slow
+# Fault-injection suites wait out real lease TTLs and retry backoffs; CI
+# runs `-m slow` in its own step with a wider per-test timeout.  The soak
+# at the end bounds its own waits and stays in the fast step.
+slow = pytest.mark.slow
 
 
 def _shard(host_id: int, root, **kw) -> DistributedStore:
@@ -42,6 +49,7 @@ def _silence(d: DistributedStore) -> None:
     d.server.close()
 
 
+@slow
 class TestStaleConnectionRecovery:
     def test_read_survives_peer_transport_restart(self, tmp_path):
         a = _shard(1, tmp_path / "pfs")
@@ -78,6 +86,7 @@ class TestStaleConnectionRecovery:
             b.close()
 
 
+@slow
 class TestOwnerDiedForwardedPut:
     def test_put_lands_via_takeover_when_owner_dies_before_send(self, tmp_path):
         a = _shard(1, tmp_path / "pfs")
@@ -119,6 +128,7 @@ class TestOwnerDiedForwardedPut:
             c.close()
 
 
+@slow
 class TestTakeoverRace:
     def test_exactly_one_winner_across_racing_hosts(self, tmp_path):
         a = _shard(1, tmp_path / "pfs")
@@ -187,6 +197,7 @@ class TestTakeoverRace:
             b.close()
 
 
+@slow
 class TestTornWrites:
     def test_torn_stripe_write_raises_and_retry_heals(self, tmp_path):
         chaos = ChaosInjector()
@@ -271,6 +282,7 @@ class TestTornWrites:
             a.close()
 
 
+@slow
 class TestCircuitBreaker:
     def test_open_circuit_degrades_reads_to_cold_then_recovers(self, tmp_path):
         a = _shard(1, tmp_path / "pfs")
@@ -311,6 +323,7 @@ class TestCircuitBreaker:
             b.close()
 
 
+@slow
 class TestBackgroundReclamation:
     def test_reclaimer_adopts_and_warms_dead_hosts_files(self, tmp_path):
         a = _shard(1, tmp_path / "pfs")
@@ -373,6 +386,7 @@ class TestBackgroundReclamation:
             b.close()
 
 
+@slow
 class TestReadChaosAndSelfHealing:
     """DESIGN.md §15: read-side chaos (bit rot, lost server dirs) against
     the replicated cold tier — read-any failover, quarantine interplay,
@@ -507,6 +521,7 @@ class TestReadChaosAndSelfHealing:
             a.close()
 
 
+@slow
 class TestHeartbeatAndLeaseFaults:
     def test_heartbeat_pause_gets_host_fenced(self, tmp_path):
         chaos = ChaosInjector()
@@ -541,3 +556,241 @@ class TestHeartbeatAndLeaseFaults:
             assert a.leases.read("f").owner == 1
         finally:
             a.close()
+
+
+# ---------------------------------------------------------------------------
+# Zero-data-loss soak: host processes under a fault schedule and a hard kill
+# ---------------------------------------------------------------------------
+
+SOAK_HOSTS = 3
+SOAK_RECLAIMER = 0  # the only host running the reclamation thread
+SOAK_VICTIM = SOAK_HOSTS - 1  # os._exit after the quiesce: no flush, no lease release
+SOAK_TTL_S = 1.5
+SOAK_WAIT_S = 120.0  # bounds every wait, so a hung host fails the test
+SOAK = dict(
+    files_per_host=8,
+    file_bytes=1 * MB,
+    write_bytes=256 * 1024,
+    mem_per_host=24 * MB,
+    block_bytes=256 * 1024,
+    fault_rounds=2,
+    writes_per_round=3,  # new files per host per round
+    updates_per_round=2,  # forwarded re-writes of a peer's files per round
+)
+# the victim's leases the reclaimer adopts: its base files and its fault-phase writes
+SOAK_VICTIM_FILES = SOAK["files_per_host"] + SOAK["fault_rounds"] * SOAK["writes_per_round"]
+
+
+def _soak_payload(name: str, version: int, nbytes: int) -> bytes:
+    """Deterministic versioned payload: any process can regenerate it."""
+    seed = (zlib.adler32(name.encode()) + 0x9E3779B1 * version) & 0xFFFFFFFF
+    return np.random.default_rng(seed).integers(0, 256, size=nbytes, dtype=np.uint8).tobytes()
+
+
+def _soak_base_names() -> list[str]:
+    return [f"soak/data_{i:04d}" for i in range(SOAK_HOSTS * SOAK["files_per_host"])]
+
+
+def _soak_owned(h: int) -> list[str]:
+    names = _soak_base_names()
+    return [n for i, n in enumerate(names) if i * SOAK_HOSTS // len(names) == h]
+
+
+def _soak_chaos_name(h: int, r: int, j: int) -> str:
+    return f"chaos/h{h}_r{r}_w{j}"
+
+
+def _soak_updated() -> set[str]:
+    """Base files re-written in the fault phase, each by one host every
+    round, so the final version is the last round's."""
+    return {n for h in range(SOAK_HOSTS) for n in _soak_owned(h)[: SOAK["updates_per_round"]]}
+
+
+def _soak_all_names() -> list[str]:
+    return _soak_base_names() + [
+        _soak_chaos_name(h, r, j)
+        for h in range(SOAK_HOSTS)
+        for r in range(SOAK["fault_rounds"])
+        for j in range(SOAK["writes_per_round"])
+    ]
+
+
+def _soak_expected(name: str) -> bytes:
+    if name.startswith("chaos/"):
+        return _soak_payload(name, 1, SOAK["write_bytes"])
+    version = SOAK["fault_rounds"] if name in _soak_updated() else 0
+    return _soak_payload(name, version, SOAK["file_bytes"])
+
+
+def _soak_retry(call, *args, attempts: int = 10):
+    """App-level retry: a put is acked only when it returns.  Torn stripes,
+    exhausted forwarded puts and fencing races raise; the count-bounded
+    schedule guarantees that a retry lands."""
+    for a in range(attempts):
+        try:
+            return call(*args)
+        except (TierError, CircuitOpen):
+            if a == attempts - 1:
+                raise
+            time.sleep(0.02 * (a + 1))
+
+
+def _soak_host(idx, root, barrier, queue, victim_dead, reclaimed, done) -> None:
+    """One host of the soak, in its own spawned process: setup, fault
+    phase, quiesce check (a barrier after each), then the victim dies and
+    every survivor re-reads the whole final state."""
+    chaos = ChaosInjector(seed=0xC0 + idx)
+    d = None
+    try:
+        d = _shard(
+            idx + 1,
+            root,
+            mem_capacity_bytes=SOAK["mem_per_host"],
+            block_bytes=SOAK["block_bytes"],
+            n_pfs_servers=4,
+            stripe_bytes=256 * 1024,
+            lease_ttl_s=SOAK_TTL_S,
+            auto_gossip=True,
+            chaos=chaos,
+            auto_reclaim=idx == SOAK_RECLAIMER,
+            reclaim_interval_s=0.25,
+            reclaim_max_files=SOAK_VICTIM_FILES,
+            reclaim_warm_bytes=256 * MB,
+        )
+        names = _soak_base_names()
+        for n in _soak_owned(idx):
+            d.put(n, _soak_payload(n, 0, SOAK["file_bytes"]))
+        d.publish_gossip()
+        barrier.wait(timeout=SOAK_WAIT_S)
+
+        chaos.arm("peer.connect", "drop", count=2)  # brief partition
+        chaos.arm("peer.request", "delay", prob=0.3, delay_s=0.02, count=20)
+        chaos.arm("peer.request", "drop", prob=0.2, count=6)
+        chaos.arm("peer.serve", "drop", prob=0.1, count=4)  # server-side frame loss
+        chaos.arm("pfs.write_unit", "torn_write", frac=0.5, prob=0.25, count=4)
+        rng = np.random.default_rng(0x5EED + idx)
+        updated = _soak_updated()
+        peer = (idx + 1) % SOAK_HOSTS  # whose files this host updates by forwarded puts
+        acked = bad = 0
+        for r in range(SOAK["fault_rounds"]):
+            writes = [
+                (n, _soak_payload(n, 1, SOAK["write_bytes"]))
+                for n in (_soak_chaos_name(idx, r, j) for j in range(SOAK["writes_per_round"]))
+            ]
+            writes += [
+                (n, _soak_payload(n, r + 1, SOAK["file_bytes"]))
+                for n in _soak_owned(peer)[: SOAK["updates_per_round"]]
+            ]
+            order = rng.permutation(len(names))
+            stride = max(1, len(order) // len(writes))
+            for k, i in enumerate(order):
+                data = _soak_retry(d.get, names[i])
+                # an updated file is mid-transition cluster-wide until the quiesce
+                if names[i] not in updated and data != _soak_payload(names[i], 0, SOAK["file_bytes"]):
+                    bad += 1
+                if k % stride == 0 and writes:
+                    _soak_retry(d.put, *writes.pop())
+                    acked += 1
+            while writes:
+                _soak_retry(d.put, *writes.pop())
+                acked += 1
+        barrier.wait(timeout=SOAK_WAIT_S)
+
+        every = _soak_all_names()
+        bad += sum(d.get(n) != _soak_expected(n) for n in every)
+        d.publish_gossip()  # a fresh hot map orders the reclaim
+        queue.put(("phases", idx, acked, bad, chaos.fired_count()))
+        barrier.wait(timeout=SOAK_WAIT_S)
+
+        if idx == SOAK_VICTIM:
+            queue.close()
+            queue.join_thread()
+            os._exit(0)
+        if idx == SOAK_RECLAIMER:
+            victim_dead.wait(timeout=SOAK_WAIT_S)
+            deadline = time.monotonic() + SOAK_WAIT_S
+            while time.monotonic() < deadline and d.stats.reclaimed_files < SOAK_VICTIM_FILES:
+                time.sleep(0.05)
+            reclaimed.set()
+        else:
+            reclaimed.wait(timeout=SOAK_WAIT_S)
+        bad_after = sum(d.get(n) != _soak_expected(n) for n in every)
+        queue.put(("after", idx, len(every), bad_after, d.stats.reclaimed_files))
+        done.wait(timeout=SOAK_WAIT_S)  # serve peers until every survivor has read
+    except BaseException:
+        queue.put(("error", idx, traceback.format_exc()))
+        try:
+            barrier.abort()  # peers fail fast instead of waiting out the barrier
+        except Exception:
+            pass
+    finally:
+        if d is not None and idx != SOAK_VICTIM:
+            d.close()
+
+
+def _soak_message(queue, deadline: float) -> tuple:
+    msg = queue.get(timeout=max(0.0, deadline - time.monotonic()))
+    if msg[0] == "error":
+        raise AssertionError(f"host {msg[1]} failed:\n{msg[2]}")
+    return msg
+
+
+def test_acked_writes_survive_fault_schedule_and_host_kill(tmp_path):
+    """Three host processes share one PFS root under mixed reads and
+    writes while partitions, delayed and dropped peer requests, dropped
+    server frames and torn stripe writes fire; then one host dies by
+    ``os._exit``.  Every acked write re-reads bit-identically from every
+    host at the quiesce, and from both survivors after the kill, the
+    victim's files through background reclamation."""
+    ctx = mp.get_context("spawn")
+    barrier = ctx.Barrier(SOAK_HOSTS + 1)
+    queue = ctx.Queue()
+    victim_dead, reclaimed, done = ctx.Event(), ctx.Event(), ctx.Event()
+    root = str(tmp_path / "pfs")
+    procs = [
+        ctx.Process(
+            target=_soak_host,
+            args=(i, root, barrier, queue, victim_dead, reclaimed, done),
+            name=f"soak-host{i}",
+        )
+        for i in range(SOAK_HOSTS)
+    ]
+    deadline = time.monotonic() + SOAK_WAIT_S
+    phases, after = {}, {}
+    for p in procs:
+        p.start()
+    try:
+        for _ in range(3):  # setup, fault phase, quiesce
+            try:
+                barrier.wait(timeout=max(0.0, deadline - time.monotonic()))
+            except threading.BrokenBarrierError:
+                _soak_message(queue, deadline)  # raises with the host's traceback
+                raise
+        while len(phases) < SOAK_HOSTS:
+            msg = _soak_message(queue, deadline)
+            phases[msg[1]] = msg[2:]
+        procs[SOAK_VICTIM].join(timeout=max(0.0, deadline - time.monotonic()))
+        assert not procs[SOAK_VICTIM].is_alive()
+        victim_dead.set()
+        while len(after) < SOAK_HOSTS - 1:
+            msg = _soak_message(queue, deadline)
+            after[msg[1]] = msg[2:]
+    finally:
+        done.set()
+        for p in procs:
+            p.join(timeout=30)
+            if p.is_alive():
+                p.terminate()
+
+    acked = sum(m[0] for m in phases.values())
+    fired = sum(m[2] for m in phases.values())
+    assert fired > 0, "the fault schedule never fired"
+    assert acked > 0, "no write was acked under faults"
+    assert acked == SOAK_HOSTS * SOAK["fault_rounds"] * (SOAK["writes_per_round"] + SOAK["updates_per_round"])
+    bad = {h: m[1] for h, m in phases.items()}
+    assert bad == dict.fromkeys(range(SOAK_HOSTS), 0), f"acked writes read back wrong per host: {bad}"
+    assert sorted(after) == [h for h in range(SOAK_HOSTS) if h != SOAK_VICTIM]
+    for checked, bad, _ in after.values():
+        assert checked == len(_soak_all_names())
+        assert bad == 0, "an acked write did not re-read bit-identically after the kill"
+    assert after[SOAK_RECLAIMER][2] >= SOAK_VICTIM_FILES

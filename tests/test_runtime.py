@@ -143,7 +143,7 @@ class TestCompression:
 
 HOST_IMPORTS_SCRIPT = """
 import sys
-import benchmarks.chaos_soak, benchmarks.multihost_scaling
+import test_dstore_chaos
 from repro.core.dstore import DistributedStore
 from repro.core.resilience import CircuitOpen
 from repro.core.tiers import TierError
@@ -154,7 +154,7 @@ print("jax" in sys.modules)
 
 
 def test_store_host_processes_never_import_jax():
-    """The store's spawned host processes (multihost/chaos benchmarks, the
+    """The store's spawned host processes (the zero-data-loss soak, the
     killed-owner test) import only these modules.  Without JAX in the
     process they cannot initialise a backend, so they never contend for
     the chip with the parent that holds it."""
@@ -163,7 +163,7 @@ def test_store_host_processes_never_import_jax():
     import sys
 
     root = os.path.join(os.path.dirname(__file__), "..")
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join([os.path.join(root, "src"), root]))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([os.path.join(root, d) for d in ("src", "tests")]))
     proc = subprocess.run(
         [sys.executable, "-c", HOST_IMPORTS_SCRIPT],
         env=env, capture_output=True, text=True, timeout=120,
